@@ -13,13 +13,15 @@ Prints ``name,us_per_call,derived`` CSV (assignment contract).
   bench_dist         beyond-paper multi-chip solver (4-dev subprocess;
                      writes BENCH_dist.json for CI's dist gate)
 
-Accuracy, refinement and distributed benches need different
-process-level settings (x64 / forced device count), so run.py re-execs
-them as subprocesses.
+Each bench runs in a process of its own, one after the other: the
+accuracy and refinement benches need x64 and the distributed bench a
+forced 4-device CPU mesh from their first jax import, and on a TPU only
+one process at a time may hold the chip. This parent process never
+imports jax. A bench that fails stops the run with its exit code.
 
 ``--smoke`` shrinks every bench to CI-sized problems (propagated to
-subprocesses via REPRO_BENCH_SMOKE=1); ``--out results.json`` writes all
-rows as a JSON artifact so CI tracks the perf trajectory per PR.
+the bench processes via REPRO_BENCH_SMOKE=1); ``--out results.json``
+writes all rows as a JSON artifact so CI tracks the trajectory per PR.
 
 ``--tune`` runs the measured-search autotuner (repro.tune) instead of
 the benches: a 4-device subprocess regenerates the committed tuning
@@ -44,6 +46,67 @@ if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
 
+#: bench -> environment its process needs from the first jax import
+_ENV = {"accuracy": {"JAX_ENABLE_X64": "1"},
+        "refine": {"JAX_ENABLE_X64": "1"},
+        "dist": {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"}}
+
+
+def _benches(smoke: bool) -> list[str]:
+    # bench_serve is left out of smoke mode: CI's bench-smoke job runs
+    # it as its own step (bench_serve.py --smoke --out bench-serve.json)
+    names = ["syrk", "trsm", "cholesky", "depth", "portability"]
+    if not smoke:
+        names.append("serve")
+    return names + ["accuracy", "refine", "dist"]
+
+
+def _run_bench(name: str, smoke: bool) -> None:
+    """Run one bench in this process (the ``--bench`` child)."""
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache(_ROOT)
+    if name in ("syrk", "trsm", "portability"):
+        import importlib
+        mod = importlib.import_module(f"benchmarks.bench_{name}")
+        if smoke:
+            mod.run(sizes=(256,))
+        else:
+            mod.run()
+    elif name == "cholesky":
+        from benchmarks import bench_cholesky
+        # tree-vs-blocked engine race; writes BENCH_cholesky.json at the
+        # repo root (CI's perf gate asserts blocked >= tree at n >= 2048)
+        if smoke:
+            bench_cholesky.run(sizes=(256,))
+            bench_cholesky.run_engines(sizes=(512, 2048))
+        else:
+            bench_cholesky.run()
+            bench_cholesky.run_engines(sizes=(512, 2048, 4096))
+    elif name == "depth":
+        from benchmarks import bench_depth
+        if smoke:
+            bench_depth.run(sizes=(256, 1024, 4096))
+        else:
+            bench_depth.run()
+    elif name == "serve":
+        from benchmarks import bench_serve
+        bench_serve.run()
+    elif name == "accuracy":
+        from benchmarks import bench_accuracy
+        bench_accuracy.run(sizes=(256,) if smoke else (1024, 2048))
+    elif name == "refine":
+        from benchmarks import bench_refine
+        if smoke:
+            bench_refine.run(sizes=(256,), methods=("ir",))
+        else:
+            bench_refine.run()
+    elif name == "dist":
+        from benchmarks import bench_dist
+        bench_dist.run(sizes=(1024, 2048) if smoke else (1024, 2048, 4096))
+    else:
+        raise SystemExit(f"unknown bench {name!r}")
+
+
 def _parse_rows(text: str):
     rows = []
     for line in text.splitlines():
@@ -58,22 +121,26 @@ def _parse_rows(text: str):
     return rows
 
 
-def _sub(module: str, env_extra: dict):
+def _sub(name: str, smoke: bool):
+    """Run bench ``name`` in its own process; exit with its code if it
+    fails."""
     env = dict(os.environ)
-    env.setdefault("PYTHONPATH", "src")
-    env.update(env_extra)
-    r = subprocess.run([sys.executable, "-m", module], env=env,
-                       capture_output=True, text=True, timeout=3000)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(_ROOT, "src"), _ROOT,
+                    env.get("PYTHONPATH")) if p)
+    env.update(_ENV.get(name, {}))
+    cmd = [sys.executable, os.path.abspath(__file__), "--bench", name]
+    if smoke:
+        cmd.append("--smoke")
+    r = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                       timeout=3000)
     sys.stdout.write(r.stdout)
-    rows = _parse_rows(r.stdout)
+    sys.stdout.flush()
     if r.returncode != 0:
-        # the failure marker must reach the JSON artifact too, so a
-        # crashed bench reads as FAILED rather than silently-absent rows
-        sys.stdout.write(f"{module},0.0,FAILED\n")
-        sys.stderr.write(r.stderr[-2000:])
-        rows.append({"name": module, "us_per_call": 0.0,
-                     "derived": "FAILED"})
-    return rows
+        sys.stderr.write(r.stderr[-4000:])
+        sys.stderr.write(f"bench {name} failed (exit {r.returncode})\n")
+        raise SystemExit(r.returncode)
+    return _parse_rows(r.stdout)
 
 
 def main(argv=None) -> None:
@@ -82,6 +149,9 @@ def main(argv=None) -> None:
                     help="tiny problem sizes (CI benchmark-smoke job)")
     ap.add_argument("--out", default=None, metavar="PATH",
                     help="write all rows as a JSON artifact")
+    ap.add_argument("--bench", default=None, metavar="NAME",
+                    help="run one bench in this process (what the "
+                         "aggregator starts for each bench)")
     ap.add_argument("--tune", action="store_true",
                     help="run the autotuner (repro.tune) instead of the "
                          "benches; writes the tuning database")
@@ -92,6 +162,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
+    if args.bench:
+        _run_bench(args.bench, args.smoke)
+        return
 
     if args.tune:
         # own process: the tuner needs the forced 4-device mesh from the
@@ -113,48 +186,19 @@ def main(argv=None) -> None:
         raise SystemExit(subprocess.run(cmd, env=env).returncode)
 
     print("name,us_per_call,derived")
-    from benchmarks import (bench_cholesky, bench_depth, bench_portability,
-                            bench_serve, bench_syrk, bench_trsm, util)
-    if args.smoke:
-        bench_syrk.run(sizes=(256,))
-        bench_trsm.run(sizes=(256,))
-        bench_cholesky.run(sizes=(256,))
-        # tree-vs-blocked engine race; writes BENCH_cholesky.json at the
-        # repo root (CI's perf gate asserts blocked >= tree at n >= 2048)
-        bench_cholesky.run_engines(sizes=(512, 2048))
-        bench_depth.run(sizes=(256, 1024, 4096))
-        bench_portability.run(sizes=(256,))
-        # bench_serve is skipped in smoke mode: CI's bench-smoke job runs
-        # it as its own step (bench_serve.py --smoke --out bench-serve.json)
-    else:
-        bench_syrk.run()
-        bench_trsm.run()
-        bench_cholesky.run()
-        bench_cholesky.run_engines(sizes=(512, 2048, 4096))
-        bench_depth.run()
-        bench_portability.run()
-        bench_serve.run()
-    sub_rows = _sub("benchmarks.bench_accuracy", {"JAX_ENABLE_X64": "1"})
-    sub_rows += _sub("benchmarks.bench_refine", {"JAX_ENABLE_X64": "1"})
-    sub_rows += _sub(
-        "benchmarks.bench_dist",
-        {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
-    # roofline table (reads experiments/dryrun if present); it prints
-    # rows directly, so tee its stdout into the artifact rows as well
-    try:
-        from benchmarks import roofline
-        buf = io.StringIO()
-        with contextlib.redirect_stdout(buf):
-            roofline.run_csv()
-        sys.stdout.write(buf.getvalue())
-        sub_rows += _parse_rows(buf.getvalue())
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline,0.0,unavailable({type(e).__name__})")
-        sub_rows.append({"name": "roofline", "us_per_call": 0.0,
-                         "derived": f"unavailable({type(e).__name__})"})
+    rows = []
+    for name in _benches(args.smoke):
+        rows += _sub(name, args.smoke)
+    # roofline table (reads experiments/dryrun if present; no jax)
+    from benchmarks import roofline
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        roofline.run_csv()
+    sys.stdout.write(buf.getvalue())
+    rows += _parse_rows(buf.getvalue())
 
     if args.out:
-        payload = {"smoke": args.smoke, "rows": list(util.ROWS) + sub_rows}
+        payload = {"smoke": args.smoke, "rows": rows}
         with open(args.out, "w") as f:
             json.dump(payload, f, indent=1)
         print(f"# wrote {len(payload['rows'])} rows to {args.out}",

@@ -62,8 +62,9 @@ _CALL_PRIMS = {"pjit", "closed_call", "core_call", "custom_jvp_call",
                "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
                "checkpoint", "scan", "while", "cond", "shard_map"}
 
-_COLLECTIVE_PRIMS = {"all_gather", "psum", "psum2", "ppermute",
-                     "all_to_all", "reduce_scatter", "psum_scatter"}
+_COLLECTIVE_PRIMS = {"all_gather", "psum", "psum2", "psum_invariant",
+                     "ppermute", "all_to_all", "reduce_scatter",
+                     "psum_scatter"}
 
 
 def ladder_name(dtype) -> str:
@@ -231,7 +232,8 @@ class _Walker:
                     tuple(eqn.outvars[0].aval.shape)))
             elif prim in _COLLECTIVE_PRIMS:
                 op = eqn.invars[0]
-                # jax names the multi-operand psum primitive "psum2"
+                # jax names the multi-operand psum primitive "psum2",
+                # and a psum under shard_map's vma checks "psum_invariant"
                 base = "psum" if prim.startswith("psum") else prim
                 self.res.collectives.append(CollectiveSite(
                     base, np.dtype(op.aval.dtype).name,

@@ -1,30 +1,16 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""shard_map typing helper shared by the Pallas kernels and the models.
 
-The repo targets the jax that ships in the image; these helpers keep it
-importable across the 0.4.x -> 0.5+ API moves without scattering
-version checks through the solver code.
+Under ``jax.shard_map`` (with its default ``check_vma=True``) every
+value carries the set of manual mesh axes it varies over (its *vma*).
+A ``pallas_call`` cannot infer that set for its outputs, so each kernel
+states it on its ``out_shape`` with :func:`vma_of` of its inputs.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    shard_map = jax.shard_map  # jax >= 0.4.38 exports it at top level
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
-
-def vma_of(x) -> frozenset:
-    """Varying-manual-axes of ``x`` (empty set before jax grew `typeof`,
-    where shard_map had no vma tracking and promotion is a no-op)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(x), "vma", frozenset())
-
-
-def pvary(x, axes):
-    """`jax.lax.pvary` where it exists; identity on older jax (whose
-    shard_map accepts collectives over unvaried axes directly)."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
+def vma_of(*xs) -> frozenset:
+    """Union of the varying manual axes of ``xs`` (empty outside
+    ``shard_map``)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))

@@ -51,13 +51,23 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.blocked import blocked_potrf, blocked_trsm_left, diag_tri_inv
 from repro.core.plan import build_plan, shard
 from repro.core.precision import PrecisionConfig
 from repro.core.quantize import quant_block, storage_round
 from repro.core.tree import tree_potrf, tree_trsm, tree_trsm_left
 from repro.kernels import ops
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _run_sharded(local, kw, mesh, in_specs, out_specs, *args):
+    """``jax.shard_map`` of ``local(*args, **dict(kw))`` under ``jit``,
+    cached on the static arguments. Called eagerly, a shard_map runs its
+    body one operation at a time, compiling each; the panel loops here
+    unroll into thousands of them."""
+    fn = functools.partial(local, **dict(kw))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)(*args)
 
 
 def _gather_panel(li, name: str, quant: bool, axis: str, compress: bool):
@@ -265,11 +275,11 @@ def dist_cholesky(a, mesh, cfg: PrecisionConfig | None = None,
             n, tune.ladder_key(cfg), nshards).compress_comm
     local = (_local_potrf_tree if cfg.engine == "tree"
              else _local_potrf_blocked)
-    fn = functools.partial(local, axis=axis, nshards=nshards, cfg=cfg,
-                           broadcast_diag_only=broadcast_diag_only,
-                           compress_comm=compress_comm)
+    kw = (("axis", axis), ("nshards", nshards), ("cfg", cfg),
+          ("broadcast_diag_only", broadcast_diag_only),
+          ("compress_comm", compress_comm))
     spec = P(axis, None)
-    return shard_map(fn, mesh=mesh, in_specs=(spec,), out_specs=spec)(a)
+    return _run_sharded(local, kw, mesh, (spec,), spec, a)
 
 
 def _local_solve(l_local, b_local, *, axis: str, nshards: int,
@@ -334,6 +344,19 @@ def _local_solve(l_local, b_local, *, axis: str, nshards: int,
     return x
 
 
+def dist_residual(a, x, b, mesh, cfg: PrecisionConfig | None = None,
+                  axis: str = "model"):
+    """Refinement residual ``b - A x`` with A and b block-row-sharded
+    over ``axis``: each device forms the residual of its own rows
+    against the whole ``x`` (gathered). The fused residual kernel runs
+    per shard because the chip's compiler cannot partition a Pallas
+    kernel on its own."""
+    cfg = cfg or PrecisionConfig()
+    spec = P(axis, None)
+    return _run_sharded(ops.residual, (("impl", cfg.kernel_impl),), mesh,
+                        (spec, P(), spec), spec, a, x, b)
+
+
 def dist_cholesky_solve(a, b, mesh, cfg: PrecisionConfig | None = None,
                         axis: str = "model", *, l=None):
     """Solve A x = b with A (and b) block-row-sharded over ``axis``."""
@@ -346,8 +369,7 @@ def dist_cholesky_solve(a, b, mesh, cfg: PrecisionConfig | None = None,
     vec = b.ndim == 1
     if vec:
         b = b[:, None]
-    fn = functools.partial(_local_solve, axis=axis, nshards=nshards, cfg=cfg)
-    x = shard_map(fn, mesh=mesh,
-                      in_specs=(P(axis, None), P(axis, None)),
-                      out_specs=P(axis, None))(l, b)
+    kw = (("axis", axis), ("nshards", nshards), ("cfg", cfg))
+    spec = P(axis, None)
+    x = _run_sharded(_local_solve, kw, mesh, (spec, spec), spec, l, b)
     return x[:, 0] if vec else x

@@ -18,13 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
@@ -100,9 +94,9 @@ def flash_attention(q, k, v, *, causal=True, bq=DEFAULT_BQ, bk=DEFAULT_BK,
 
     nq, nk = Sp // bq, Tp // bk
     grid = (H, nq, nk)
-    scratch = ([pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, hd), jnp.float32)] if _HAS_PLTPU else [])
+    scratch = [pltpu.VMEM((bq, 128), jnp.float32),
+               pltpu.VMEM((bq, 128), jnp.float32),
+               pltpu.VMEM((bq, hd), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, nk=nk, bq=bq, bk=bk,
                           causal=causal),

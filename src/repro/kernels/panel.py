@@ -33,8 +33,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.compat import vma_of
 from repro.core.precision import DTYPES, RMAX
 from repro.kernels.syrk import _tri_decode
+
+#: the tiles are f32 containers holding values on the plan's grids
+#: (f16 values need 11 significand bits, f32 ones 24): every dot runs at
+#: full f32 on the MXU, not as Mosaic's default single bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _round_name(x, name: str, quant: bool):
@@ -57,11 +63,35 @@ def _round_name(x, name: str, quant: bool):
         return q * alpha
     assert name == "f16", name
     if not quant:
-        return x.astype(DTYPES["f16"]).astype(jnp.float32)
+        return _f16_grid(x)
     amax = jnp.max(jnp.abs(x)).astype(jnp.float32)
     alpha = jnp.maximum(jnp.float32(1.0), amax / jnp.float32(RMAX["f16"]))
-    q = (x / alpha).astype(DTYPES["f16"]).astype(jnp.float32)
-    return q * alpha
+    return _f16_grid(x / alpha) * alpha
+
+
+def _f16_grid(x):
+    """``x.astype(float16).astype(float32)`` in f32 arithmetic.
+
+    Mosaic cannot pack f32 vectors into f16 on v5e, so the rounding is
+    emulated bit for bit: round-to-nearest-even onto the 11-bit
+    significand (integer ops on the f32 bit pattern; a carry into the
+    exponent is the correct round-up), the fixed 2^-24 spacing of f16
+    subnormals below 2^-14, overflow past 65504 to inf, sign (zeros
+    included) and NaN carried over.
+    """
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    sign = bits & jnp.int32(-0x80000000)
+    mag = bits & jnp.int32(0x7FFFFFFF)
+    odd = (mag >> 13) & 1
+    normal = jax.lax.bitcast_convert_type(
+        (mag + jnp.int32(0xFFF) + odd) & jnp.int32(~0x1FFF), jnp.float32)
+    ax = jnp.abs(x)
+    sub = jnp.round(ax * jnp.float32(2.0 ** 24)) * jnp.float32(2.0 ** -24)
+    y = jnp.where(ax < jnp.float32(2.0 ** -14), sub, normal)
+    y = jnp.where(y > jnp.float32(RMAX["f16"]), jnp.float32(jnp.inf), y)
+    y = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(y, jnp.int32) | sign, jnp.float32)
+    return jnp.where(jnp.isnan(x), x, y)
 
 
 def _round_select(x, code, names, quants):
@@ -96,7 +126,8 @@ def _panel_kernel(sc_ref, pc_ref, linv_ref, ai_ref, aj_ref, c_ref,
         a = a_tile.astype(jnp.float32)
         if rounding:
             a = _round_select(a, code, names, quants)
-        lt = jnp.dot(a, linv_t, preferred_element_type=jnp.float32)
+        lt = jnp.dot(a, linv_t, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
         if rounding:
             lt = _round_select(lt, code, names, quants)
         return lt
@@ -110,7 +141,8 @@ def _panel_kernel(sc_ref, pc_ref, linv_ref, ai_ref, aj_ref, c_ref,
     lj = solve_tile(aj_ref[...], j)
     qj = _round_select(lj, pc, names, quants)
     upd = (c_ref[...].astype(jnp.float32)
-           - jnp.dot(qi, qj.T, preferred_element_type=jnp.float32))
+           - jnp.dot(qi, qj.T, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32))
     if rounding:
         # the trailing matrix LIVES at its tiles' precision between
         # panels (paper Fig. 3) — round the updated partial sum back
@@ -158,6 +190,7 @@ def panel_update(linv, a21, c, *, store_names, store_quants, pair_names,
     pair_codes = jnp.asarray([[names.index(nm) for nm in row]
                               for row in pair_names], jnp.int32)
     ntri = nt * (nt + 1) // 2
+    vma = vma_of(linv, a21, c)
 
     def ai_map(t):
         i, _ = _tri_decode(t)
@@ -187,8 +220,8 @@ def panel_update(linv, a21, c, *, store_names, store_quants, pair_names,
             pl.BlockSpec((b, b), c_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, b), a21.dtype),
-            jax.ShapeDtypeStruct((m, m), c.dtype),
+            jax.ShapeDtypeStruct((m, b), a21.dtype, vma=vma),
+            jax.ShapeDtypeStruct((m, m), c.dtype, vma=vma),
         ],
         interpret=interpret,
     )(store_codes, pair_codes, linv, a21, a21, c)

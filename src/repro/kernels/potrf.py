@@ -21,75 +21,92 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.compat import vma_of
 
 MICRO = 128  # diagonal micro-panel, matches MXU/VREG lane width
+#: every dot here is an f32 leaf step of the ladder's high level: pin it
+#: to full f32 on the MXU, not Mosaic's default single bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _chol_micro(a):
-    """Vectorised unblocked Cholesky of a (m, m) tile; returns lower L."""
+    """Vectorised unblocked Cholesky of a (m, m) tile; returns lower L.
+
+    Column ``j`` is read and written through masked-iota selects and
+    reductions over the whole tile (only the selected entries are
+    nonzero, so every sum is exact): Mosaic has no lowering for
+    ``dynamic_slice`` on a traced loop index.
+    """
     m = a.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
+    rvec = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
 
     def body(j, a):
-        piv = jax.lax.dynamic_slice(a, (j, j), (1, 1))
-        d = jnp.sqrt(piv)
-        col = jax.lax.dynamic_slice_in_dim(a, j, 1, axis=1)  # (m, 1)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
-        col = jnp.where(rows >= j, col / d, 0.0)
-        a = jax.lax.dynamic_update_slice_in_dim(a, col, j, axis=1)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-        upd = jnp.where(cols > j, col * col.reshape(1, m), 0.0)
+        col = jnp.sum(jnp.where(cols == j, a, 0.0), axis=1,
+                      keepdims=True)                                # (m, 1)
+        d = jnp.sqrt(jnp.sum(jnp.where(rvec == j, col, 0.0), axis=0,
+                             keepdims=True))                        # (1, 1)
+        col = jnp.where(rvec >= j, col / d, 0.0)
+        a = jnp.where(cols == j, col, a)
+        # col as a row vector, via the diagonal (no transpose/reshape)
+        row = jnp.sum(jnp.where(rows == cols, col, 0.0), axis=0,
+                      keepdims=True)                                # (1, m)
+        upd = jnp.where(cols > j, col * row, 0.0)
         return a - upd
 
     a = jax.lax.fori_loop(0, m, body, a)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
     return jnp.where(rows >= cols, a, 0.0)
 
 
 def _tri_inv_micro(l):
-    """X = L^-1 for lower-triangular (m, m) via row-wise forward subst."""
+    """X = L^-1 for lower-triangular (m, m) via row-wise forward subst.
+
+    Row ``i`` of ``L`` is read and row ``i`` of ``X`` written through
+    masked-iota selects, as in :func:`_chol_micro`.
+    """
     m = l.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
     x0 = jnp.zeros_like(l)
 
     def body(i, x):
-        li = jax.lax.dynamic_slice_in_dim(l, i, 1, axis=0)      # (1, m)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+        li = jnp.sum(jnp.where(rows == i, l, 0.0), axis=0,
+                     keepdims=True)                                 # (1, m)
         li_strict = jnp.where(cols < i, li, 0.0)
-        s = jnp.dot(li_strict, x, preferred_element_type=jnp.float32)
+        s = jnp.dot(li_strict, x, precision=_HIGHEST,
+                    preferred_element_type=jnp.float32)
         e = (cols == i).astype(l.dtype)
-        lii = jax.lax.dynamic_slice(l, (i, i), (1, 1))
+        lii = jnp.sum(jnp.where(cols == i, li, 0.0), axis=1,
+                      keepdims=True)                                # (1, 1)
         row = (e - s.astype(l.dtype)) / lii
-        return jax.lax.dynamic_update_slice_in_dim(x, row, i, axis=0)
+        return jnp.where(rows == i, row, x)
 
     return jax.lax.fori_loop(0, m, body, x0)
 
 
-def _dus(a, val, i0, j0):
-    """Static-offset block write (jnp's .at[slice].set creates an empty
-    index constant inside pallas kernels; DUS does not)."""
-    return jax.lax.dynamic_update_slice(a, val, (i0, j0))
-
-
-def _potrf_kernel(a_ref, o_ref, *, b):
-    a = a_ref[...].astype(jnp.float32)
+def _potrf_kernel(a_ref, o_ref, w_ref, *, b):
+    # ``w_ref`` is the f32 working tile in VMEM; block writes go through
+    # static ref slices (Mosaic lowers no dynamic_update_slice)
+    w_ref[...] = a_ref[...].astype(jnp.float32)
     nb = b // MICRO
     for j in range(nb):  # python-unrolled: static shapes per panel
-        j0 = j * MICRO
-        ajj = a[j0:j0 + MICRO, j0:j0 + MICRO]
-        l = _chol_micro(ajj)
-        a = _dus(a, l, j0, j0)
+        j0, j1 = j * MICRO, (j + 1) * MICRO
+        l = _chol_micro(w_ref[j0:j1, j0:j1])
+        w_ref[j0:j1, j0:j1] = l
         if j < nb - 1:
             linv = _tri_inv_micro(l)
-            below = a[j0 + MICRO:, j0:j0 + MICRO]
-            panel = jnp.dot(below, linv.T, preferred_element_type=jnp.float32)
-            a = _dus(a, panel, j0 + MICRO, j0)
-            trail = a[j0 + MICRO:, j0 + MICRO:]
-            trail = trail - jnp.dot(panel, panel.T,
-                                    preferred_element_type=jnp.float32)
-            a = _dus(a, trail, j0 + MICRO, j0 + MICRO)
+            panel = jnp.dot(w_ref[j1:, j0:j1], linv.T, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
+            w_ref[j1:, j0:j1] = panel
+            w_ref[j1:, j1:] = w_ref[j1:, j1:] - jnp.dot(
+                panel, panel.T, precision=_HIGHEST,
+                preferred_element_type=jnp.float32)
     rows = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
-    o_ref[...] = jnp.where(rows >= cols, a, 0.0).astype(o_ref.dtype)
+    o_ref[...] = jnp.where(rows >= cols, w_ref[...], 0.0).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -101,36 +118,37 @@ def potrf_leaf(a, *, interpret=False):
         functools.partial(_potrf_kernel, b=n),
         in_specs=[pl.BlockSpec((n, n), lambda: (0, 0))],
         out_specs=pl.BlockSpec((n, n), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, n), a.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, n), a.dtype,
+                                       vma=vma_of(a)),
+        scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
     )(a)
 
 
-def _tri_inv_kernel(l_ref, o_ref, *, b):
-    l = l_ref[...].astype(jnp.float32)
+def _tri_inv_kernel(l_ref, o_ref, x_ref, *, b):
     nb = b // MICRO
     # Diagonal micro-inverses, then blocked forward substitution:
     #   X[i,j] = -inv_i @ ( sum_{j<=k<i} L[i,k] X[k,j] )
+    # with X accumulated in the f32 VMEM tile ``x_ref``.
     invs = []
     for i in range(nb):
-        i0 = i * MICRO
-        invs.append(_tri_inv_micro(l[i0:i0 + MICRO, i0:i0 + MICRO]))
-    x = jnp.zeros((b, b), jnp.float32)
+        i0, i1 = i * MICRO, (i + 1) * MICRO
+        invs.append(_tri_inv_micro(l_ref[i0:i1, i0:i1].astype(jnp.float32)))
+    x_ref[...] = jnp.zeros((b, b), jnp.float32)
     for j in range(nb):
-        j0 = j * MICRO
-        x = _dus(x, invs[j], j0, j0)
+        j0, j1 = j * MICRO, (j + 1) * MICRO
+        x_ref[j0:j1, j0:j1] = invs[j]
         for i in range(j + 1, nb):
-            i0 = i * MICRO
+            i0, i1 = i * MICRO, (i + 1) * MICRO
             s = jnp.zeros((MICRO, MICRO), jnp.float32)
             for k in range(j, i):
-                k0 = k * MICRO
-                s = s + jnp.dot(l[i0:i0 + MICRO, k0:k0 + MICRO],
-                                x[k0:k0 + MICRO, j0:j0 + MICRO],
+                k0, k1 = k * MICRO, (k + 1) * MICRO
+                s = s + jnp.dot(l_ref[i0:i1, k0:k1].astype(jnp.float32),
+                                x_ref[k0:k1, j0:j1], precision=_HIGHEST,
                                 preferred_element_type=jnp.float32)
-            x = _dus(x, -jnp.dot(invs[i], s,
-                                 preferred_element_type=jnp.float32),
-                     i0, j0)
-    o_ref[...] = x.astype(o_ref.dtype)
+            x_ref[i0:i1, j0:j1] = -jnp.dot(invs[i], s, precision=_HIGHEST,
+                                           preferred_element_type=jnp.float32)
+    o_ref[...] = x_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -142,6 +160,8 @@ def tri_inv_leaf(l, *, interpret=False):
         functools.partial(_tri_inv_kernel, b=n),
         in_specs=[pl.BlockSpec((n, n), lambda: (0, 0))],
         out_specs=pl.BlockSpec((n, n), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, n), l.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, n), l.dtype,
+                                       vma=vma_of(l)),
+        scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
     )(l)

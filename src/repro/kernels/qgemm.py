@@ -18,15 +18,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import vma_of
 from repro.core.precision import DTYPES
-
-try:  # TPU-specific bits are optional so interpret mode works anywhere.
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 # Default tile sizes: MXU-aligned (multiples of 128), working set
 # 2*(bm*bk + bk*bn)*2B + bm*bn*4B ~ 1.3 MB << 16 MB VMEM.
@@ -47,7 +42,11 @@ def _kernel(s_ref, a_ref, b_ref, o_ref, acc_ref, *, trans_b, nk, has_c,
     b = b_ref[...]
     if trans_b:
         b = b.T
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    # f32 operands (the ladder's high-precision solves) run at full f32
+    # on the MXU; Mosaic takes no precision for narrow operands
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    acc_ref[...] += jnp.dot(a, b, precision=prec,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -62,19 +61,6 @@ def _kernel(s_ref, a_ref, b_ref, o_ref, acc_ref, *, trans_b, nk, has_c,
 def _kernel_with_c(s_ref, a_ref, b_ref, c_ref, o_ref, acc_ref, *, trans_b, nk):
     _kernel(s_ref, a_ref, b_ref, o_ref, acc_ref, trans_b=trans_b, nk=nk,
             has_c=True, c_ref=c_ref)
-
-
-def _compiler_params():
-    if not _HAS_PLTPU:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    try:
-        return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except TypeError:  # pragma: no cover - API drift guard
-        return None
 
 
 @functools.partial(
@@ -142,23 +128,17 @@ def qgemm(a, b, scale, *, c=None, beta=0.0, trans_b=False,
         kernel = functools.partial(_kernel, trans_b=trans_b, nk=nk,
                                    has_c=False)
 
-    scratch = ([pltpu.VMEM((bm, bn), jnp.float32)] if _HAS_PLTPU
-               else [pl.MemorySpace.ANY((bm, bn), jnp.float32)])  # pragma: no cover
-
-    params = {}
-    cp = _compiler_params()
-    if cp is not None and not interpret:
-        params["compiler_params"] = cp
-
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=scratch,
+        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype,
+                                       vma=vma_of(*operands)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **params,
     )(*operands)
     if (Mp, Np) != (M, N):
         out = out[:M, :N]

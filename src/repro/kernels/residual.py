@@ -22,15 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-from repro.kernels import ref as _ref
+from repro.compat import vma_of
 
 DEFAULT_BM = 256
 DEFAULT_BK = 512
@@ -45,7 +39,11 @@ def _residual_kernel(a_ref, x_ref, b_ref, o_ref, acc_ref, *, nk):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], x_ref[...],
+    # the residual is the refinement's f32 yardstick: f32 operands run at
+    # full f32 on the MXU (Mosaic takes no precision for narrow ones)
+    a = a_ref[...]
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    acc_ref[...] += jnp.dot(a, x_ref[...], precision=prec,
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -57,35 +55,35 @@ def _residual_kernel(a_ref, x_ref, b_ref, o_ref, acc_ref, *, nk):
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
 def residual_fused(a, x, b, *, bm=DEFAULT_BM, bk=DEFAULT_BK,
                    interpret=False):
-    """Fused r = b - a @ x. a: (n, n); x, b: (n,) or (n, k).
+    """Fused r = b - a @ x. a: (m, n); x: (n,) or (n, k); b: (m,) or
+    (m, k). A row block of a square system (m < n) is what each device
+    forms on the distributed path.
 
-    Grid = (n/bm, n/bk); each row-block accumulates its k-panels in an
+    Grid = (m/bm, n/bk); each row-block accumulates its k-panels in an
     f32 VMEM scratch and subtracts from b in the epilogue. Inputs are
     zero-padded to tile/lane multiples and the result sliced back, so
-    arbitrary n and k are accepted.
+    arbitrary m, n and k are accepted.
     """
-    if not _HAS_PLTPU:  # pragma: no cover — the k-accumulation needs
-        return _ref.residual_ref(a, x, b)  # the VMEM scratch to exist
     vec = x.ndim == 1
     if vec:
         x, b = x[:, None], b[:, None]
     n, kc = x.shape
-    assert a.shape == (n, n) and b.shape == (n, kc), (a.shape, b.shape)
-    bm, bk = min(bm, n), min(bk, n)
-    npad = -(-n // bm) * bm          # row blocking of A / b / r
+    m = a.shape[0]
+    assert a.shape == (m, n) and b.shape == (m, kc), (a.shape, b.shape)
+    bm, bk = min(bm, m), min(bk, n)
+    mpad = -(-m // bm) * bm          # row blocking of A / b / r
     kpad = -(-n // bk) * bk          # contraction blocking of A / x
     cpad = -(-kc // LANE) * LANE
-    if (npad, kpad) != (n, n):
-        a = jnp.pad(a, ((0, npad - n), (0, kpad - n)))
+    if (mpad, kpad) != (m, n):
+        a = jnp.pad(a, ((0, mpad - m), (0, kpad - n)))
     if kpad != n:
         x = jnp.pad(x, ((0, kpad - n), (0, 0)))
-    if npad != n:
-        b = jnp.pad(b, ((0, npad - n), (0, 0)))
+    if mpad != m:
+        b = jnp.pad(b, ((0, mpad - m), (0, 0)))
     if cpad != kc:
         x = jnp.pad(x, ((0, 0), (0, cpad - kc)))
         b = jnp.pad(b, ((0, 0), (0, cpad - kc)))
-    nm, nk = npad // bm, kpad // bk
-    scratch = [pltpu.VMEM((bm, cpad), jnp.float32)]
+    nm, nk = mpad // bm, kpad // bk
     out = pl.pallas_call(
         functools.partial(_residual_kernel, nk=nk),
         grid=(nm, nk),
@@ -95,9 +93,10 @@ def residual_fused(a, x, b, *, bm=DEFAULT_BM, bk=DEFAULT_BK,
             pl.BlockSpec((bm, cpad), lambda i, k: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, cpad), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, cpad), b.dtype),
-        scratch_shapes=scratch,
+        out_shape=jax.ShapeDtypeStruct((mpad, cpad), b.dtype,
+                                       vma=vma_of(a, x, b)),
+        scratch_shapes=[pltpu.VMEM((bm, cpad), jnp.float32)],
         interpret=interpret,
     )(a, x, b)
-    out = out[:n, :kc]
+    out = out[:m, :kc]
     return out[:, 0] if vec else out

@@ -20,15 +20,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import vma_of
 from repro.core.precision import DTYPES
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
 
 DEFAULT_BN = 256
 DEFAULT_BK = 512
@@ -78,7 +73,6 @@ def syrk_leaf(c, a, scale, beta, *, bk=DEFAULT_BK, interpret=False):
     nk = Kp // bk
     s = jnp.stack([jnp.asarray(scale, jnp.float32),
                    jnp.asarray(beta, jnp.float32)]).reshape(2, 1)
-    scratch = ([pltpu.VMEM((n, n), jnp.float32)] if _HAS_PLTPU else [])
     return pl.pallas_call(
         functools.partial(_syrk_leaf_kernel, nk=nk),
         grid=(nk,),
@@ -88,8 +82,9 @@ def syrk_leaf(c, a, scale, beta, *, bk=DEFAULT_BK, interpret=False):
             pl.BlockSpec((n, n), lambda k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((n, n), lambda k: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, n), c.dtype),
-        scratch_shapes=scratch,
+        out_shape=jax.ShapeDtypeStruct((n, n), c.dtype,
+                                       vma=vma_of(s, a, c)),
+        scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
     )(s, a, c)
 
@@ -171,7 +166,6 @@ def syrk_packed(c, a, scale, beta, *, bn=DEFAULT_BN, bk=DEFAULT_BK,
         i, j = _tri_decode(t)
         return (i, j)
 
-    scratch = ([pltpu.VMEM((bn, bn), jnp.float32)] if _HAS_PLTPU else [])
     out = pl.pallas_call(
         functools.partial(_syrk_packed_kernel, nk=nk, bn=bn),
         grid=(ntri, nk),
@@ -182,8 +176,9 @@ def syrk_packed(c, a, scale, beta, *, bn=DEFAULT_BN, bk=DEFAULT_BK,
             pl.BlockSpec((bn, bn), c_map),
         ],
         out_specs=pl.BlockSpec((bn, bn), c_map),
-        out_shape=jax.ShapeDtypeStruct((npad, npad), c.dtype),
-        scratch_shapes=scratch,
+        out_shape=jax.ShapeDtypeStruct((npad, npad), c.dtype,
+                                       vma=vma_of(s, a, c)),
+        scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
         interpret=interpret,
     )(s, a, a, c)
     # Off-triangle tiles of the padded output were never visited; restore

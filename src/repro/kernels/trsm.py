@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.compat import vma_of
 from repro.kernels.potrf import tri_inv_leaf
 
 DEFAULT_BM = 512
@@ -25,7 +26,10 @@ def _trsm_kernel(b_ref, linv_ref, o_ref, *, trans):
     linv = linv_ref[...]
     if trans:
         linv = linv.T
-    o_ref[...] = jnp.dot(b, linv,
+    # f32 panels solve at full f32 on the MXU (Mosaic takes no precision
+    # for narrow operands)
+    prec = jax.lax.Precision.HIGHEST if b.dtype == jnp.float32 else None
+    o_ref[...] = jnp.dot(b, linv, precision=prec,
                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -57,7 +61,8 @@ def trsm_leaf(b, l=None, *, linv=None, bm=DEFAULT_BM, interpret=False):
             pl.BlockSpec((n, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, n), b.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, n), b.dtype,
+                                       vma=vma_of(b, linv)),
         interpret=interpret,
     )(b, linv.astype(b.dtype))
     return out[:M] if Mp != M else out

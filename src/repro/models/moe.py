@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
-from repro.compat import shard_map
 from repro.models.common import ModelConfig, Sharder, _init
 
 
@@ -108,7 +107,7 @@ def _moe_local(x, router_w, w_in, w_gate, w_out, *, cfg: ModelConfig,
         have = compat.vma_of(aux)
         missing = tuple(a for a in all_axes if a not in have)
         if missing:
-            aux = compat.pvary(aux, missing)
+            aux = jax.lax.pcast(aux, missing, to="varying")
         aux = jax.lax.pmean(aux, all_axes)
     return out.reshape(B, S, D).astype(x.dtype), aux
 
@@ -123,7 +122,7 @@ def moe_ffn(x, p, cfg: ModelConfig, sharder: Sharder):
         fn = functools.partial(_moe_local, cfg=cfg, ep=ep,
                                axis=sharder.model_axis,
                                all_axes=tuple(mesh.axis_names))
-        routed, aux = shard_map(
+        routed, aux = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(pspec_x, P(None, None),
                       P(sharder.model_axis, None, None),

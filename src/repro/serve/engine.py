@@ -31,7 +31,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.blocked import diag_tri_inv
-from repro.core.distributed import dist_cholesky, dist_cholesky_solve
+from repro.core.distributed import (dist_cholesky, dist_cholesky_solve,
+                                    dist_residual)
 from repro.core.precision import PAPER_CONFIGS, PrecisionConfig
 from repro.core.refine import (RefineConfig, RefineResult, RefineStepper,
                                gmres_operator, refine_operator, scaled_solve)
@@ -317,8 +318,9 @@ class SolverEngine:
         backs the single-device path), but the base solve and every
         sweep's correction go through
         :func:`~repro.core.distributed.dist_cholesky_solve` against the
-        sharded factor; residuals form in the residual precision via the
-        fused-residual dispatch like the local path.
+        sharded factor; residuals form in the residual precision through
+        :func:`~repro.core.distributed.dist_residual`, each device's rows
+        by the fused-residual dispatch like the local path.
         """
         rdtype = rcfg.rdtype()
         mesh, axis = self.mesh, self.dist_axis
@@ -339,7 +341,7 @@ class SolverEngine:
             return a_r @ x
 
         def resid(x):
-            return ops.residual(a_r, x, b_r, impl=cfg.kernel_impl)
+            return dist_residual(a_r, x, b_r, mesh, cfg, axis=axis)
 
         correct = scaled_solve(base_solve)
         x0 = base_solve(b_r)    # unscaled, like iterative_refine

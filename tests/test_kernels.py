@@ -53,6 +53,19 @@ def test_residual_fused(n, k):
                                rtol=2e-4, atol=2e-3)
 
 
+@pytest.mark.parametrize("m,n,k", [(256, 1024, 8), (100, 300, 3)])
+def test_residual_fused_row_block(m, n, k):
+    """A row block of the system (each device's rows on the distributed
+    path) against the whole x."""
+    a = _rand((m, n))
+    x = _rand((n, k))
+    b = _rand((m, k))
+    got = ops.residual(a, x, b, impl="interpret")
+    want = ref.residual_ref(a, x, b)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-3)
+
+
 def test_residual_fused_vector():
     n = 200
     a = _rand((n, n))
@@ -141,8 +154,7 @@ def test_trsm_leaf_accepts_precomputed_linv():
 def test_residual_f64_routes_to_oracle():
     """f64 residuals (the x64 accuracy path) must bypass the fused
     kernel's f32 accumulator bit-for-bit."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 96
         a = jnp.asarray(RNG.standard_normal((n, n)))
         x = jnp.asarray(RNG.standard_normal(n))

@@ -2,10 +2,10 @@
 across precision ladders (f64 reference under jax_enable_x64), the
 zero-sweep no-op contract, the operator-level API used by K-FAC, and the
 accuracy-targeted serve engine."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 import repro.core as core
 
@@ -26,7 +26,7 @@ LADDERS = ["pure_f16", "f16_f32", "bf16_f32"]
 def test_refine_converges_x64(ladder, method):
     """Every cheap ladder must reach ~f64 working accuracy: residuals in
     f64, corrections through the low-precision factor."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 512
         a = spd(n)
         b = a @ np.random.default_rng(1).standard_normal(n)
@@ -44,7 +44,7 @@ def test_refine_converges_x64(ladder, method):
 def test_acceptance_f16_f32_5_sweeps():
     """ISSUE acceptance: 1024x1024 well-conditioned SPD, f16_f32 ladder,
     classic IR hits relative residual <= 1e-10 within 5 sweeps."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 1024
         a = spd(n, seed=3)
         b = a @ np.random.default_rng(3).standard_normal(n)
@@ -121,7 +121,7 @@ def test_stall_tolerates_one_flat_sweep():
     inverse (skewed stale preconditioners, GMRES-IR first restarts) do
     exactly this: the residual GROWS on sweep one, then collapses. Two
     consecutive non-improving sweeps are now required to exit."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 64
         a = _ill_conditioned_spd(n, 1e6, seed=3)
         ainv = np.linalg.inv(a)
@@ -146,7 +146,7 @@ def test_stall_exits_diverging_run_with_best_iterate():
     """A genuinely diverging iteration (residual doubling every sweep)
     must exit after exactly two non-improving sweeps with the best
     iterate — not burn max_sweeps."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 64
         a = _ill_conditioned_spd(n, 1e4, seed=5)
         # A @ correct = -I, so r -> 2 r: divergence from sweep one
@@ -166,7 +166,7 @@ def test_multi_rhs_per_column_convergence():
     """Columns with different per-column tolerances converge at
     different sweep counts; converged columns freeze (nan history)
     while slower neighbors keep sweeping."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 256
         a = spd(n)
         b = a @ np.random.default_rng(2).standard_normal((n, 3))
@@ -192,7 +192,7 @@ def test_multi_rhs_per_column_convergence():
 def test_slow_steady_convergence_is_not_stalled():
     """A run that improves EVERY sweep — however slowly — must never be
     stalled out: stall needs two consecutive sweeps with no new best."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 32
         # A = I, correct = 0.375 I  =>  r' = 0.625 r (a new best each sweep)
         b = jnp.asarray(np.random.default_rng(3).standard_normal(n))
@@ -327,7 +327,7 @@ def test_kfac_refine_sweeps_improves_whitening():
 
 def test_gmres_beats_ir_when_factor_is_poor():
     """GMRES-IR tolerates a preconditioner too weak for classic IR."""
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 256
         a = spd(n, seed=17)
         # degrade the preconditioner far beyond ladder quality

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from repro import checkpoint as ckpt
-from repro.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.data import SyntheticLM
 from repro.models.common import ModelConfig
@@ -140,7 +139,7 @@ def test_ef_compression_dp_trainer():
         g, res = compress.ef_allreduce_mean({"w": g}, {"w": res}, "dp")
         return w - lr * g["w"], res["w"][None]
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P("dp"), P("dp"), P("dp"), P()),
         out_specs=(P(), P("dp"))))
