@@ -34,6 +34,7 @@ and run in the ladder's high precision over the stored (rounded) factor.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.plan import build_plan
@@ -83,23 +84,32 @@ def blocked_potrf(a, cfg: PrecisionConfig, *, plan=None):
     # kernel also keeps the trailing update tile-resident in VMEM.
     trail = a
     out = jnp.zeros((n, n), a.dtype)
+    # Named scopes split each step into the leaf factor and inverse
+    # (``diag``), the fused update with its slices (``panel``) and the
+    # writes into the output (``write``), as a profile reads them.
     for p in range(T):
         r0 = p * b
         name_p = plan.name(p, p)
-        diag = _round(_sym_from_lower(trail[:b, :b]), name_p, cfg)
-        lpp = ops.potrf(diag.astype(high), impl=cfg.kernel_impl)
-        lpp = _round(lpp.astype(a.dtype), name_p, cfg)
-        out = out.at[r0:r0 + b, r0:r0 + b].set(lpp)
+        with jax.named_scope("diag"):
+            diag = _round(_sym_from_lower(trail[:b, :b]), name_p, cfg)
+            lpp = ops.potrf(diag.astype(high), impl=cfg.kernel_impl)
+            lpp = _round(lpp.astype(a.dtype), name_p, cfg)
+        with jax.named_scope("write"):
+            out = out.at[r0:r0 + b, r0:r0 + b].set(lpp)
         if p < T - 1:
-            linv = ops.tri_inv(lpp.astype(high), impl=cfg.kernel_impl)
+            with jax.named_scope("diag"):
+                linv = ops.tri_inv(lpp.astype(high), impl=cfg.kernel_impl)
             meta = plan.panel_meta(p)
-            l21, trail = ops.panel_update(
-                linv.astype(a.dtype), trail[b:, :b], trail[b:, b:],
-                store_names=meta.store_names,
-                store_quants=meta.store_quants,
-                pair_names=meta.pair_names, pair_quants=meta.pair_quants,
-                rounding=cfg.storage_rounding, impl=cfg.kernel_impl)
-            out = out.at[r0 + b:, r0:r0 + b].set(l21)
+            with jax.named_scope("panel"):
+                l21, trail = ops.panel_update(
+                    linv.astype(a.dtype), trail[b:, :b], trail[b:, b:],
+                    store_names=meta.store_names,
+                    store_quants=meta.store_quants,
+                    pair_names=meta.pair_names,
+                    pair_quants=meta.pair_quants,
+                    rounding=cfg.storage_rounding, impl=cfg.kernel_impl)
+            with jax.named_scope("write"):
+                out = out.at[r0 + b:, r0:r0 + b].set(l21)
     return out
 
 
@@ -119,6 +129,7 @@ def diag_tri_inv(l, cfg: PrecisionConfig):
         for i in range(n // b)])
 
 
+@jax.named_scope("solve")
 def blocked_trsm_left(bmat, l, cfg: PrecisionConfig, *, trans: bool,
                       linvs=None):
     """Flat left triangular solve against a blocked factor.

@@ -38,12 +38,14 @@ fused into a single Pallas kernel on TPU — see
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import annotate_function
 
 from repro.core.precision import DTYPES, PrecisionConfig
 from repro.core.solve import cholesky_padded, solve_factored
@@ -129,6 +131,7 @@ def _colnorm(v):
     return jnp.linalg.norm(v, axis=0) if v.ndim == 2 else jnp.linalg.norm(v)
 
 
+@jax.named_scope("sweep")
 def _masked_sweep(sweep: Callable, resid: Callable, relnorm: Callable,
                   x, r, rel, bx, brel, its, stall, act):
     """One per-column-masked refinement sweep — the shared inner step.
@@ -277,6 +280,7 @@ class RefineStepper:
                          its=jnp.zeros((s,), jnp.int32),
                          stall=jnp.zeros((s,), jnp.int32))
 
+    @functools.partial(annotate_function, name="repro.refine.join")
     def join(self, state: SlotState, idx, b_cols, x0_cols,
              tols) -> SlotState:
         """Insert columns into free slots mid-flight.
@@ -498,6 +502,7 @@ def _as_refine_config(refine) -> RefineConfig:
     raise TypeError(f"refine must be int | RefineConfig | None: {refine!r}")
 
 
+@jax.named_scope("refine")
 def iterative_refine(a, b, cfg: PrecisionConfig | None = None,
                      refine: int | RefineConfig | None = None, *,
                      l=None, col_tol=None, linvs=None) -> RefineResult:

@@ -47,6 +47,7 @@ def _trsm_left(b, l, cfg: PrecisionConfig, *, trans, linvs=None):
     return tree_trsm_left(b, l, cfg, trans=trans)
 
 
+@jax.named_scope("factor")
 def cholesky(a, cfg: PrecisionConfig | None = None):
     """Lower Cholesky factor via the mixed-precision engine selected by
     ``cfg.engine``. Handles arbitrary n by identity-padding to the leaf
@@ -56,6 +57,7 @@ def cholesky(a, cfg: PrecisionConfig | None = None):
     return cholesky_padded(a, cfg)[:n, :n]
 
 
+@jax.named_scope("factor")
 def cholesky_padded(a, cfg: PrecisionConfig | None = None):
     """Leaf-padded lower factor (identity tail, shape a multiple of
     ``cfg.leaf``) — the form the solve paths and factor caches consume
@@ -66,6 +68,7 @@ def cholesky_padded(a, cfg: PrecisionConfig | None = None):
     return _potrf(a_p, _autoresolve(cfg, a_p.shape[-1]))
 
 
+@jax.named_scope("solve")
 def cholesky_solve(a, b, cfg: PrecisionConfig | None = None, *, l=None,
                    refine=None, linvs=None):
     """Solve A x = b for SPD A via L (L^T x) = b.

@@ -110,6 +110,7 @@ def flash_attention(q, k, v, *, causal=True, bq=DEFAULT_BQ, bk=DEFAULT_BK,
         out_shape=jax.ShapeDtypeStruct((H, Sp, hd), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :S] if Sp != S else out
 

@@ -224,6 +224,7 @@ def panel_update(linv, a21, c, *, store_names, store_quants, pair_names,
             jax.ShapeDtypeStruct((m, m), c.dtype, vma=vma),
         ],
         interpret=interpret,
+        name="panel_update",
     )(store_codes, pair_codes, linv, a21, a21, c)
     # Upper trailing tiles were never visited; restore them from the
     # input so callers see an intact upper triangle (syrk_packed idiom).

@@ -122,6 +122,7 @@ def potrf_leaf(a, *, interpret=False):
                                        vma=vma_of(a)),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
+        name="potrf_leaf",
     )(a)
 
 
@@ -164,4 +165,5 @@ def tri_inv_leaf(l, *, interpret=False):
                                        vma=vma_of(l)),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
+        name="tri_inv_leaf",
     )(l)

@@ -139,6 +139,7 @@ def qgemm(a, b, scale, *, c=None, beta=0.0, trans_b=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="qgemm",
     )(*operands)
     if (Mp, Np) != (M, N):
         out = out[:M, :N]

@@ -97,6 +97,7 @@ def residual_fused(a, x, b, *, bm=DEFAULT_BM, bk=DEFAULT_BK,
                                        vma=vma_of(a, x, b)),
         scratch_shapes=[pltpu.VMEM((bm, cpad), jnp.float32)],
         interpret=interpret,
+        name="residual_fused",
     )(a, x, b)
     out = out[:m, :kc]
     return out[:, 0] if vec else out

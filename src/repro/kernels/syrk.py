@@ -86,6 +86,7 @@ def syrk_leaf(c, a, scale, beta, *, bk=DEFAULT_BK, interpret=False):
                                        vma=vma_of(s, a, c)),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
+        name="syrk_leaf",
     )(s, a, c)
 
 
@@ -180,6 +181,7 @@ def syrk_packed(c, a, scale, beta, *, bn=DEFAULT_BN, bk=DEFAULT_BK,
                                        vma=vma_of(s, a, c)),
         scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
         interpret=interpret,
+        name="syrk_packed",
     )(s, a, a, c)
     # Off-triangle tiles of the padded output were never visited; restore
     # them from the input so callers see an intact upper triangle.

@@ -64,5 +64,6 @@ def trsm_leaf(b, l=None, *, linv=None, bm=DEFAULT_BM, interpret=False):
         out_shape=jax.ShapeDtypeStruct((Mp, n), b.dtype,
                                        vma=vma_of(b, linv)),
         interpret=interpret,
+        name="trsm_leaf",
     )(b, linv.astype(b.dtype))
     return out[:M] if Mp != M else out

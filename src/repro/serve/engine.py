@@ -28,6 +28,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.blocked import diag_tri_inv
@@ -348,6 +349,7 @@ class SolverEngine:
         run = gmres_operator if rcfg.method == "gmres" else refine_operator
         return run(matvec, correct, b_r, x0, rcfg, resid=resid, tol=col_tol)
 
+    @functools.partial(annotate_function, name="repro.engine.factor")
     def factor(self, a, cache_key=None, *, fingerprint=None):
         """Factorize (or fetch the cached factor for) ``a``.
 
@@ -482,6 +484,7 @@ class SolverEngine:
             off += k
         return xs, infos
 
+    @functools.partial(annotate_function, name="repro.engine.stepper")
     def continuous_stepper(self, a, *, slots: int, cache_key=None,
                            fingerprint=None):
         """Factor ``a`` (through the cache) and return the continuous-
@@ -521,14 +524,20 @@ class SolverEngine:
         rdtype = rcfg.rdtype()
         a_r = jnp.asarray(a, rdtype)
 
-        def base_solve(r):
+        def solve(r):
             return solve_factored(l, r.astype(l.dtype), cfg,
                                   linvs=linvs).astype(rdtype)
+
+        def base_solve(r):
+            # the eager solve of a joining block, on the host loop; the
+            # stepper's sweep traces ``solve`` under jit instead
+            with TraceAnnotation("repro.solve.base"):
+                return solve(r)
 
         def resid(x, b):
             return ops.residual(a_r, x, b, impl=cfg.kernel_impl)
 
-        stepper = RefineStepper(scaled_solve(base_solve), resid,
+        stepper = RefineStepper(scaled_solve(solve), resid,
                                 n=n, slots=slots, rcfg=rcfg)
         with self._cache_lock:
             self._steppers[memo_key] = (stepper, base_solve)

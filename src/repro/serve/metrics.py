@@ -14,7 +14,7 @@ Emitted series (see docs/SERVING.md, "Continuous batching" → metrics):
 =============================  =====  ==========================================
 name                           kind   meaning
 =============================  =====  ==========================================
-``engine.requests``            count  RHS batches entering ``solve_batched``
+``engine.requests``            count  requests solved by ``solve_batched``
 ``engine.factor_cache_hit``    count  cached factor reused
 ``engine.factor_cache_miss``   count  factorization actually ran
 ``engine.sweeps_per_column``   obs    refinement sweeps spent, per RHS column
@@ -26,6 +26,9 @@ name                           kind   meaning
 ``frontend.requests``          count  admissions through the frontend
 ``frontend.shed``              count  load-shed events, labelled ``tier=``
 =============================  =====  ==========================================
+
+``engine.requests`` counts the entries of ``bs`` (one per request; a
+multi-column block counts once), not RHS columns, and carries no labels.
 """
 from __future__ import annotations
 

@@ -78,10 +78,17 @@ from typing import Any
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.engine import SolveInfo, SolverEngine, matrix_fingerprint
 from repro.serve.metrics import MetricsTracker
 from repro.serve.options import SolveOptions, resolve_options
+
+
+def _rids(ids) -> str:
+    """Request ids for a span's ``rid``, space-separated (the profiler
+    splits annotation values at commas)."""
+    return " ".join(map(str, ids))
 
 
 class SchedulerOverload(RuntimeError):
@@ -429,8 +436,9 @@ class BatchScheduler:
         """
         while True:
             with self._cv:
-                while not self._queue and not self._stop_flag:
-                    self._cv.wait()
+                with TraceAnnotation("repro.sched.wait"):
+                    while not self._queue and not self._stop_flag:
+                        self._cv.wait()
                 if not self._queue:         # stop requested, queue empty
                     return
                 head = self._queue[0]
@@ -467,18 +475,25 @@ class BatchScheduler:
             state = self._cb_expire(stepper, state, slot_owner, live)
             if not live:
                 continue
-            if stepper.active_mask(state).any():
-                state, stepped = stepper.step(state)
-                self.metrics.inc("scheduler.sweeps")
-                rel = np.asarray(state.rel)
-                for s in np.flatnonzero(stepped):
-                    owner = slot_owner[s]
-                    if owner is not None:
-                        live[owner[0]].hist[owner[1]].append(float(rel[s]))
+            with TraceAnnotation("repro.sched.masks"):
+                active = stepper.active_mask(state).any()
+            if active:
+                with TraceAnnotation("repro.sched.step"):
+                    state, stepped = stepper.step(state)
+                    self.metrics.inc("scheduler.sweeps")
+                    rel = np.asarray(state.rel)
+                    for s in np.flatnonzero(stepped):
+                        owner = slot_owner[s]
+                        if owner is not None:
+                            live[owner[0]].hist[owner[1]].append(
+                                float(rel[s]))
+            # from the host's slot table: no device sync per sweep
             self.metrics.gauge(
                 "scheduler.slot_occupancy",
-                float(np.asarray(state.occ).sum()) / self.max_batch)
-            done = [s for s in np.flatnonzero(stepper.done_mask(state))
+                sum(o is not None for o in slot_owner) / self.max_batch)
+            with TraceAnnotation("repro.sched.masks"):
+                finished = stepper.done_mask(state)
+            done = [s for s in np.flatnonzero(finished)
                     if slot_owner[s] is not None]
             state = self._cb_retire(stepper, state, slot_owner, live, done,
                                     expired=False)
@@ -507,33 +522,37 @@ class BatchScheduler:
                 self._cv.notify_all()
         if not take:
             return state
-        now = time.monotonic()
-        free = [i for i, o in enumerate(slot_owner) if o is None]
-        bblk = jnp.concatenate(
-            [r.b[:, None] if r.b.ndim == 1 else r.b for r in take],
-            axis=1).astype(stepper.rdtype)
-        x0 = base_solve(bblk)               # the window path's x0, unscaled
-        tols = np.concatenate([
-            np.full(r.n_cols, 10.0 ** -self.engine._clamp(r.target_digits))
-            for r in take])
-        used = free[:bblk.shape[1]]
-        state = stepper.join(state, used, bblk, x0, tols)
-        rel = np.asarray(state.rel)
-        pos = 0
-        for r in take:
-            rslots = used[pos:pos + r.n_cols]
-            pos += r.n_cols
-            for ci, s in enumerate(rslots):
-                slot_owner[s] = (r.request_id, ci)
-            qms = (now - r.submitted_at) * 1e3
-            live[r.request_id] = _LiveRequest(
-                req=r, slots=list(rslots), queue_ms=qms,
-                deadline=(r.submitted_at + r.deadline_ms / 1e3
-                          if r.deadline_ms is not None else None),
-                cached=cached,
-                hist={ci: [float(rel[s])] for ci, s in enumerate(rslots)})
-            self.metrics.observe("scheduler.queue_ms", qms)
-        return state
+        with TraceAnnotation("repro.sched.admit",
+                             rid=_rids(r.request_id for r in take)):
+            now = time.monotonic()
+            free = [i for i, o in enumerate(slot_owner) if o is None]
+            bblk = jnp.concatenate(
+                [r.b[:, None] if r.b.ndim == 1 else r.b for r in take],
+                axis=1).astype(stepper.rdtype)
+            x0 = base_solve(bblk)           # the window path's x0, unscaled
+            tols = np.concatenate([
+                np.full(r.n_cols,
+                        10.0 ** -self.engine._clamp(r.target_digits))
+                for r in take])
+            used = free[:bblk.shape[1]]
+            state = stepper.join(state, used, bblk, x0, tols)
+            rel = np.asarray(state.rel)
+            pos = 0
+            for r in take:
+                rslots = used[pos:pos + r.n_cols]
+                pos += r.n_cols
+                for ci, s in enumerate(rslots):
+                    slot_owner[s] = (r.request_id, ci)
+                qms = (now - r.submitted_at) * 1e3
+                live[r.request_id] = _LiveRequest(
+                    req=r, slots=list(rslots), queue_ms=qms,
+                    deadline=(r.submitted_at + r.deadline_ms / 1e3
+                              if r.deadline_ms is not None else None),
+                    cached=cached,
+                    hist={ci: [float(rel[s])]
+                          for ci, s in enumerate(rslots)})
+                self.metrics.observe("scheduler.queue_ms", qms)
+            return state
 
     def _cb_expire(self, stepper, state, slot_owner, live):
         """Force-retire live requests whose deadline has passed; they
@@ -551,19 +570,21 @@ class BatchScheduler:
         """Retire ``slots`` and resolve requests with no columns left."""
         if not slots:
             return state
-        state, results = stepper.retire(state, slots)
-        finished = set()
-        for s, res in zip(slots, results):
-            rid, ci = slot_owner[s]
-            slot_owner[s] = None
-            lv = live[rid]
-            lv.slots.remove(s)
-            lv.cols[ci] = res
-            lv.expired = lv.expired or expired
-            if not lv.slots:
-                finished.add(rid)
-        for rid in finished:
-            self._cb_resolve(live.pop(rid))
+        rids = sorted({slot_owner[s][0] for s in slots})
+        with TraceAnnotation("repro.sched.retire", rid=_rids(rids)):
+            state, results = stepper.retire(state, slots)
+            finished = set()
+            for s, res in zip(slots, results):
+                rid, ci = slot_owner[s]
+                slot_owner[s] = None
+                lv = live[rid]
+                lv.slots.remove(s)
+                lv.cols[ci] = res
+                lv.expired = lv.expired or expired
+                if not lv.slots:
+                    finished.add(rid)
+            for rid in finished:
+                self._cb_resolve(live.pop(rid))
         return state
 
     def _cb_resolve(self, lv: _LiveRequest):

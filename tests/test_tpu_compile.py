@@ -12,6 +12,7 @@ module is imported): only one process at a time may load the TPU
 library, and pytest-xdist workers all import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -115,3 +116,39 @@ def test_sharded_panel_update_compiles(topo):
                                     sharding=NamedSharding(mesh, spec))
     _compile_has_kernel(fn, s((LEAF, LEAF), P()), s((4 * M, LEAF), P("model")),
                         s((4 * M, M), P("model")))
+
+
+def _kernel_calls(fn, *args):
+    """Names of the compiled program's Pallas custom-calls, without the
+    number XLA appends."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return {re.sub(r"\.\d+$", "", m.group(1)) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+
+
+@pytest.mark.parametrize("kernel", ["panel_update", "potrf_leaf",
+                                    "tri_inv_leaf", "residual_fused",
+                                    "qgemm"])
+def test_kernel_keeps_the_name_the_benchmark_reads(one_chip, kernel):
+    """The benchmark finds each kernel in a device trace by its custom-
+    call's name (``bench/metrics``: ``factor.kernel_ms``,
+    ``panel_update_roofline``, ``residual_fused_roofline``): a renamed
+    kernel would read ``null`` there, so the names are pinned."""
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    calls = {
+        "panel_update": lambda: (_panel_fn("bf16_f32"), s((LEAF, LEAF)),
+                                 s((M, LEAF)), s((M, M))),
+        "potrf_leaf": lambda: (potrf.potrf_leaf, s((LEAF, LEAF))),
+        "tri_inv_leaf": lambda: (potrf.tri_inv_leaf, s((LEAF, LEAF))),
+        "residual_fused": lambda: (residual.residual_fused, s((M, M)),
+                                   s((M, 8)), s((M, 8))),
+        "qgemm": lambda: (lambda a, b: qgemm.qgemm(a, b, 1.0),
+                          s((LEAF, LEAF)), s((LEAF, LEAF))),
+    }
+    fn, *args = calls[kernel]()
+
+    def program(*xs):          # a caller of another name around it
+        return fn(*xs)
+    assert _kernel_calls(program, *args) == {kernel}
