@@ -111,6 +111,29 @@ def matrix_fingerprint(a, samples: int = 8):
     return (a.shape, str(a.dtype), np.asarray(probe).tobytes())
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _base_solve(l, linvs, r, cfg: PrecisionConfig):
+    """The unscaled factored solve of a block ``r``, as one program.
+
+    ``l`` and ``linvs`` are arguments, never constants of the program
+    (JAX writes a closed-over array into the module as a dense
+    constant), so one executable serves every factor of its shape and
+    JAX's own cache compiles once per block width.
+    """
+    return solve_factored(l, r.astype(l.dtype), cfg,
+                          linvs=linvs).astype(r.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "rcfg"))
+def _refine(a, bmat, l, linvs, col_tol, cfg: PrecisionConfig,
+            rcfg: RefineConfig) -> RefineResult:
+    """Single-device :func:`refine_solve` as one program: the matrix,
+    the block, the factor and the per-column tolerances are arguments,
+    so a new target mix or a new factor of the same shape reuses it."""
+    return refine_solve(a, bmat, cfg, refine=rcfg, l=l, col_tol=col_tol,
+                        linvs=linvs)
+
+
 def _strip_history(h):
     """Nan-padded ``[sweeps+1, k]`` history -> per-column float tuples.
 
@@ -442,8 +465,9 @@ class SolverEngine:
             assert col_tol.shape == (sum(cols),), (col_tol.shape, cols)
         else:
             col_tol = np.repeat([10.0 ** -d for d in digits], cols)
-        rcfg = RefineConfig(max_sweeps=self.max_sweeps,
-                            tol=float(col_tol.min()), method=method,
+        # ``col_tol`` governs convergence; ``rcfg`` holds no per-request
+        # value, so it can key the compiled refine program
+        rcfg = RefineConfig(max_sweeps=self.max_sweeps, method=method,
                             gmres_restart=self.gmres_restart)
         l, linvs, cached = self.factor(a, opts.cache_key,
                                        fingerprint=opts.fingerprint)
@@ -454,9 +478,8 @@ class SolverEngine:
             res: RefineResult = self._dist_refine(
                 a, bmat, rcfg, l, jnp.asarray(col_tol))
         else:
-            res = refine_solve(a, bmat, self._cfg_for(n), refine=rcfg,
-                               l=l, col_tol=jnp.asarray(col_tol),
-                               linvs=linvs)
+            res = _refine(a, bmat, l, linvs, jnp.asarray(col_tol),
+                          cfg=self._cfg_for(n), rcfg=rcfg)
         sweeps = np.atleast_1d(np.asarray(res.iterations))
         resid = np.atleast_1d(np.asarray(res.residual))
         conv = np.atleast_1d(np.asarray(res.converged))
@@ -496,6 +519,9 @@ class SolverEngine:
         ``base_solve`` computes the initial iterate for joining columns
         (the same unscaled factored solve the windowed path starts
         from, so a column's trajectory is identical in either mode).
+        It runs as one compiled program per join width, the factor an
+        argument; each width a stepper meets first adds one to
+        ``engine.base_solve_compiles``.
         Classic IR only — GMRES-IR's joint Krylov space cannot retire
         columns mid-restart — and single-device only (the scheduler
         windows distributed-path requests).
@@ -528,11 +554,18 @@ class SolverEngine:
             return solve_factored(l, r.astype(l.dtype), cfg,
                                   linvs=linvs).astype(rdtype)
 
+        widths: set = set()
+
         def base_solve(r):
-            # the eager solve of a joining block, on the host loop; the
-            # stepper's sweep traces ``solve`` under jit instead
+            # one compiled program per join width, the factor an argument
+            r = jnp.asarray(r, rdtype)
             with TraceAnnotation("repro.solve.base"):
-                return solve(r)
+                with self._cache_lock:
+                    new = r.shape not in widths
+                    widths.add(r.shape)
+                if new:
+                    self.metrics.inc("engine.base_solve_compiles")
+                return _base_solve(l, linvs, r, cfg=cfg)
 
         def resid(x, b):
             return ops.residual(a_r, x, b, impl=cfg.kernel_impl)

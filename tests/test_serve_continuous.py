@@ -14,6 +14,7 @@ from __future__ import annotations
 import threading
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -90,6 +91,74 @@ def test_continuous_blockwidth_invariance(eng):
     (x2, i2), (x4, i4) = outs
     assert np.array_equal(np.asarray(x2), np.asarray(x4))
     assert i2.history == i4.history
+
+
+# ---------------------------------------------------------------------------
+# the continuous base solve: one program per join width, factor an argument
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_base_solve_matches_window_x0(eng, k):
+    """The stepper's compiled base solve gives the windowed path's
+    initial iterate bit for bit. A 1-digit target is met before any
+    sweep, so the windowed call returns its x0 unchanged."""
+    a = _spd(seed=11)
+    blk = _rhs(a, seed=k, k=k)
+    (x_w,), (info,) = eng.solve_batched(
+        a, [blk], SolveOptions(target_digits=1.0, cache_key="x0"))
+    assert info.sweeps == 0
+    stepper, base_solve, _ = eng.continuous_stepper(a, slots=3,
+                                                    cache_key="x0")
+    x0 = base_solve(blk.astype(stepper.rdtype))
+    assert np.array_equal(np.asarray(x0), np.asarray(x_w))
+
+
+def test_base_solve_compiles_counts_join_widths():
+    """``engine.base_solve_compiles`` counts each join width a stepper
+    meets once: two lone admits share width 1, a 2-column block adds
+    width 2."""
+    a = _spd(seed=12)
+    mt = InMemoryMetrics()
+    eng2 = SolverEngine("f16_f32", max_sweeps=8, metrics=mt)
+    sch = BatchScheduler(eng2, max_batch=4, continuous=True)
+    sch.start()
+    opts = SolveOptions(target_digits=4.0, cache_key="compiles")
+    try:
+        for i in range(2):
+            sch.submit_async(a, _rhs(a, seed=i), opts).result(timeout=120)
+        assert mt.snapshot()["counters"]["engine.base_solve_compiles"] == 1
+        sch.submit_async(a, _rhs(a, seed=2, k=2), opts).result(timeout=120)
+        assert mt.snapshot()["counters"]["engine.base_solve_compiles"] == 2
+    finally:
+        sch.stop()
+
+
+def test_base_solve_takes_the_factor_as_an_argument(eng):
+    """The compiled base solve's module takes ``l`` and ``linvs`` as
+    entry arguments and holds no constant of their shapes. Closing over
+    them instead writes them in as dense constants, which the same check
+    catches. Two leaves, so every panel reads the factor."""
+    from repro.serve.engine import _base_solve
+
+    n = 2 * eng.cfg.leaf
+    a = _spd(n=n, seed=13)
+    l, linvs, _ = eng.factor(a, cache_key="hlo")
+    cfg = eng._cfg_for(n)
+    r = np.ones((n, 1), np.float32)
+    types = [f"tensor<{'x'.join(map(str, x.shape))}x"
+             for x in jax.tree.leaves((l, linvs))]
+
+    def constants(text):
+        return [t for t in types for ln in text.splitlines()
+                if "stablehlo.constant" in ln and t in ln]
+
+    text = _base_solve.lower(l, linvs, r, cfg=cfg).as_text()
+    main = next(ln for ln in text.splitlines() if "func.func public" in ln)
+    assert all(t in main for t in types), main   # entry arguments
+    assert not constants(text)
+    assert len(text) < l.size                    # no element written out
+
+    closed = jax.jit(lambda rr: _base_solve(l, linvs, rr, cfg=cfg))
+    assert sorted(set(constants(closed.lower(r).as_text()))) == sorted(types)
 
 
 # ---------------------------------------------------------------------------
