@@ -14,10 +14,11 @@ the static expectations :class:`repro.core.plan.PrecisionPlan` exposes:
   f16<->bf16 double-round and no promotion wider than the container.
 * ``audit_solve`` / ``audit_refine`` — the triangular solves and the
   refinement loop are lossless: all dots wide, zero rounding events.
-* ``audit_dist`` — the distributed panel sweep's collectives: exactly
-  ``P-1`` panel gathers whose wire dtype matches
-  ``ShardedPlan.comm_name(j)`` panel by panel, ``P`` diagonal psums, and
-  scale gathers exactly where the plan quantizes the wire.
+* ``audit_dist`` — the distributed panel sweep's collectives: one
+  gather of the (w, b) panel slices for every b-wide panel but the
+  last, each on the wire dtype ``ShardedPlan.comm_name(j)`` gives it,
+  one (b, b) diagonal psum per panel, and scale gathers exactly where
+  the plan quantizes the wire.
 """
 from __future__ import annotations
 
@@ -256,29 +257,29 @@ def audit_dist(n: int, cfg: PrecisionConfig, nshards: int, *,
             severity="warn")])
     mesh = Mesh(np.array(devs[:nshards]), ("model",))
     sp = sharded or ShardedPlan(build_plan(n, cfg), nshards)
-    w = n // nshards
+    w, b, npan = n // nshards, sp.panel_width, sp.npanels
     (a,) = _structs((n, n))
     res = dtypeflow.trace(
         lambda x: dist_cholesky(x, mesh, cfg, compress_comm=compress), a)
 
     viols = []
     gathers = [c for c in res.collectives
-               if c.prim == "all_gather" and c.shape == (w, w)]
+               if c.prim == "all_gather" and c.shape == (w, b)]
     scale_gathers = [c for c in res.collectives
                      if c.prim == "all_gather" and c.shape == ()]
     psums = [c for c in res.collectives
-             if c.prim == "psum" and c.shape == (w, w)]
-    if len(gathers) != nshards - 1:
+             if c.prim == "psum" and c.shape == (b, b)]
+    if len(gathers) != npan - 1:
         viols.append(Violation(
             "collective-count", target,
-            f"traced {len(gathers)} (w, w) panel gathers, schedule has "
-            f"{nshards - 1}"))
-    if len(psums) != nshards:
+            f"traced {len(gathers)} (w, b) panel gathers, schedule has "
+            f"{npan - 1}"))
+    if len(psums) != npan:
         viols.append(Violation(
             "collective-count", target,
-            f"traced {len(psums)} diagonal psums, schedule has {nshards}"))
+            f"traced {len(psums)} diagonal psums, schedule has {npan}"))
     expect_scales = 0
-    for j, g in enumerate(gathers[:nshards - 1]):
+    for j, g in enumerate(gathers[:npan - 1]):
         nm, q = sp.comm_name(j), sp.comm_quant(j)
         want_wire = WIRE_DTYPE[nm] if compress else "f32"
         got_wire = NP_TO_HLO.get(g.wire, g.wire)

@@ -11,9 +11,10 @@ prevent). These checks parse ``compiled.as_text()`` through the extended
 * total dot FLOPs against ``PrecisionPlan.expected_dot_flops`` (exact:
   the blocked schedule's GEMMs all survive as HLO dots on every backend
   we compile for),
-* per-wire-dtype collective bytes against ``ShardedPlan.comm_table()``
-  (exact: P-1 panel gathers + P diagonal all-reduces + one (P,) f32
-  scale gather per quantized panel),
+* per-wire-dtype collective bytes against
+  ``ShardedPlan.collective_bytes()`` (exact: one gather of the (w, b)
+  panel slices per b-wide panel but the last, one (b, b) diagonal
+  all-reduce per panel, one (P,) f32 scale gather per quantized panel),
 * per-operand-dtype dot FLOPs, reported as a *warning* on CPU — XLA CPU
   legally promotes f16/bf16 dots into f32 containers (the value-level
   rounding still applies), so narrow dot dtypes only appear on MXU
@@ -24,7 +25,6 @@ from __future__ import annotations
 import numpy as np
 
 from repro.audit.report import CheckResult, Violation
-from repro.core.dtypes import BYTES, WIRE_DTYPE
 from repro.core.plan import ShardedPlan, build_plan
 from repro.core.precision import PrecisionConfig
 
@@ -95,19 +95,10 @@ def audit_hlo_dist(n: int, cfg: PrecisionConfig, nshards: int, *,
     del jnp
     cen = census(hlo)
     sp = sharded or ShardedPlan(build_plan(n, cfg), nshards)
-    w = n // nshards
-
-    exp: dict[str, float] = {}
-    # P diagonal broadcasts: psum of the masked (w, w) block -> f32
-    # all-reduce per panel
-    exp["f32"] = float(nshards * w * w * 4)
-    n_scale = 0
-    for row in sp.comm_table()[:nshards - 1]:
-        wire = row["wire"] if compress else "f32"
-        exp[wire] = exp.get(wire, 0.0) + float(nshards * w * w * BYTES[wire])
-        if compress and row["quant"]:
-            exp["f32"] += nshards * 4           # (P,) f32 scale gather
-            n_scale += 1
+    npan = sp.npanels
+    exp = sp.collective_bytes(compress)
+    n_scale = sum(1 for row in sp.comm_table()[:npan - 1]
+                  if compress and row["quant"])
 
     got = cen["collective_bytes_by_dtype"]
     viols = []
@@ -115,7 +106,7 @@ def audit_hlo_dist(n: int, cfg: PrecisionConfig, nshards: int, *,
         g, e = got.get(dt, 0.0), exp.get(dt, 0.0)
         if g == e:
             continue
-        panels = [row["panel"] for row in sp.comm_table()[:nshards - 1]
+        panels = [row["panel"] for row in sp.comm_table()[:npan - 1]
                   if (row["wire"] if compress else "f32") == dt]
         viols.append(Violation(
             "hlo-collective-bytes", target,
@@ -124,15 +115,15 @@ def audit_hlo_dist(n: int, cfg: PrecisionConfig, nshards: int, *,
             "convert commuted across the collective or a gather changed "
             "wire dtype"))
     counts = cen["collectives"]
-    want_ag = (nshards - 1) + (n_scale if compress else 0)
+    want_ag = (npan - 1) + n_scale
     if counts["all-gather"]["count"] != want_ag:
         viols.append(Violation(
             "hlo-collective-bytes", target,
             f"compiled all-gather count {counts['all-gather']['count']:.0f}"
             f" != scheduled {want_ag}"))
-    if counts["all-reduce"]["count"] != nshards:
+    if counts["all-reduce"]["count"] != npan:
         viols.append(Violation(
             "hlo-collective-bytes", target,
             f"compiled all-reduce count {counts['all-reduce']['count']:.0f}"
-            f" != scheduled {nshards} diagonal broadcasts"))
+            f" != scheduled {npan} diagonal broadcasts"))
     return CheckResult("hlo-dist", target, viols)

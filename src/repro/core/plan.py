@@ -309,15 +309,48 @@ class PanelMeta:
     pair_quants: tuple
 
 
+#: widest panel the distributed sweep takes (see :func:`panel_width`)
+PANEL_MAX = 2048
+
+
+def panel_width(n: int, nshards: int, leaf: int) -> int:
+    """Width b of the distributed sweep's panels: the largest multiple of
+    ``leaf`` that divides the shard's rows ``w = n / nshards`` and is at
+    most :data:`PANEL_MAX`.
+
+    Why 2048: a panel's trailing update is a GEMM with inner dimension
+    b that reads and writes each trailing element once per panel, so it
+    does b/4 flops per f32 byte of the trailing matrix; past the v5e's
+    ridge (197e12 / 819e9, about 240) that needs b of about 1000, and
+    2048 leaves twice that. Wider panels buy nothing more and cost on
+    three sides that grow with b: the gathered panel (n x b, 512 MiB in
+    f32 at n = 65536), the diagonal block every shard refactors
+    redundantly (n b^2 / 3 flops a shard over the sweep: 0.4% of the
+    n^3 / (3P) useful at n = 65536, P = 4) and the serial chain of
+    diagonal factor and TRSM each panel waits on.
+    """
+    assert n % nshards == 0, (n, nshards)
+    w = n // nshards
+    assert w % leaf == 0, (w, leaf)
+    b = max(leaf, (min(w, PANEL_MAX) // leaf) * leaf)
+    while w % b:
+        b -= leaf
+    return b
+
+
 class ShardedPlan:
-    """Block-row partition of a :class:`PrecisionPlan` over ``nshards``.
+    """Block-row partition of a :class:`PrecisionPlan` over ``nshards``,
+    swept in panels of :func:`panel_width` columns.
 
     The distributed solver (:mod:`repro.core.distributed`) lays the
     global matrix out in 1-D block rows: shard ``s`` owns tile rows
-    ``[s*tps, (s+1)*tps)`` with ``tps = ntiles // nshards``, and panel
-    ``j`` is the j-th ``(w, w)`` block column, ``w = n // nshards``.
-    This view answers the three questions that layout asks of the
-    precision map, all statically (pure numpy, no array ops):
+    ``[s*tps, (s+1)*tps)`` with ``tps = ntiles // nshards`` (``w = n //
+    nshards`` rows). Its sweep takes panels of ``b`` columns
+    (:func:`panel_width`; ``b`` divides ``w``), so panel
+    ``j`` is the block column ``[j*b, (j+1)*b)`` of ``ptiles = b //
+    leaf`` tiles, owned by shard ``j*b // w``. This view answers the
+    three questions that layout asks of the precision map, all
+    statically (pure numpy, no array ops):
 
     * :meth:`diag_plan` — the tile-square sub-plan of panel ``j``'s
       diagonal block, so the redundant local factorization computes each
@@ -343,7 +376,11 @@ class ShardedPlan:
         self.cfg = plan.cfg
         self.nshards = nshards
         self.tps = plan.ntiles // nshards       # tile rows per shard
-        self.panel_width = plan.n // nshards
+        self.shard_rows = plan.n // nshards     # w
+        panel = panel_width(plan.n, nshards, plan.leaf)
+        self.panel_width = panel                # b
+        self.ptiles = panel // plan.leaf        # tiles per panel
+        self.npanels = plan.n // panel
         #: static code alphabet for store_codes tables (sorted dtype
         #: names actually present in the plan's storage map)
         self.names = tuple(sorted(
@@ -354,26 +391,31 @@ class ShardedPlan:
     def row_tiles(self, s: int) -> range:
         return range(s * self.tps, (s + 1) * self.tps)
 
+    def owner(self, j: int) -> int:
+        """Shard that holds panel ``j``'s diagonal block."""
+        return j * self.panel_width // self.shard_rows
+
     def store_codes(self, j: int) -> np.ndarray:
-        """(ntiles, tps) int32 table: ``codes[i, c]`` indexes
-        :attr:`names` with the storage dtype of tile ``(i, j*tps + c)``.
-        All shards share the table; shard ``s`` reads rows
+        """(ntiles, ptiles) int32 table: ``codes[i, c]`` indexes
+        :attr:`names` with the storage dtype of tile ``(i, j*ptiles +
+        c)``. All shards share the table; shard ``s`` reads rows
         ``s*tps .. (s+1)*tps`` (a traced index under shard_map)."""
-        cols = self.plan.store_levels[:, j * self.tps:(j + 1) * self.tps]
+        cols = self.plan.store_levels[:, j * self.ptiles:
+                                      (j + 1) * self.ptiles]
         lut = {lv: self.names.index(self.cfg.name_at(int(lv)))
                for lv in np.unique(cols)}
         return np.vectorize(lut.__getitem__, otypes=[np.int32])(cols)
 
     # -- local engine view -------------------------------------------------
     def diag_plan(self, j: int) -> PrecisionPlan:
-        return self.plan.subplan(j * self.tps, (j + 1) * self.tps)
+        return self.plan.subplan(j * self.ptiles, (j + 1) * self.ptiles)
 
     # -- collective precision ----------------------------------------------
     def comm_level(self, j: int) -> int:
         """Coarsest compute level among trailing consumers of panel
         ``j``'s gathered column (lower-triangle pairs strictly below the
         panel). The last panel has no consumers: highest level."""
-        lo = (j + 1) * self.tps
+        lo = (j + 1) * self.ptiles
         T = self.plan.ntiles
         if lo >= T:
             return int(self.plan.levels.max())
@@ -395,24 +437,42 @@ class ShardedPlan:
             {"panel": j, "name": self.comm_name(j),
              "quant": self.comm_quant(j),
              "wire": WIRE_DTYPE[self.comm_name(j)]}
-            for j in range(self.nshards))
+            for j in range(self.npanels))
+
+    def collective_bytes(self, compress: bool = True) -> dict:
+        """Bytes the factor's collectives deliver to each shard, by HLO
+        wire dtype: per panel the (b, b) f32 diagonal all-reduce, and for
+        every panel but the last the all-gather of the (w, b) panel
+        slices (``nshards * w * b`` elements on its wire) plus, where the
+        wire is quantized, the (nshards,) f32 scale gather. The compiled-
+        HLO audit reconciles against this exactly."""
+        b, w, P = self.panel_width, self.shard_rows, self.nshards
+        out = {"f32": float(self.npanels * b * b * BYTES["f32"])}
+        for row in self.comm_table()[:-1]:
+            wire = row["wire"] if compress else "f32"
+            out[wire] = out.get(wire, 0.0) + float(P * w * b * BYTES[wire])
+            if compress and row["quant"]:
+                out["f32"] += P * BYTES["f32"]
+        return out
 
     def describe(self) -> str:
         """Per-panel collective schedule (docs/ARCHITECTURE.md)."""
         lines = [f"ShardedPlan(nshards={self.nshards}, tps={self.tps}, "
-                 f"w={self.panel_width}, ladder={self.cfg.describe()})"]
-        for j in range(self.nshards):
+                 f"w={self.shard_rows}, b={self.panel_width}, "
+                 f"ladder={self.cfg.describe()})"]
+        for j in range(self.npanels):
             lines.append(f"  panel {j}: comm={self.comm_name(j)}"
                          f"{' (quantized)' if self.comm_quant(j) else ''}")
         return "\n".join(lines)
 
     def __repr__(self):
         return (f"ShardedPlan(n={self.plan.n}, nshards={self.nshards}, "
-                f"ladder={self.cfg.describe()})")
+                f"b={self.panel_width}, ladder={self.cfg.describe()})")
 
 
 def shard(plan: PrecisionPlan, nshards: int) -> ShardedPlan:
-    """Block-row partition view of ``plan`` for an ``nshards`` mesh axis."""
+    """Block-row partition view of ``plan`` for an ``nshards`` mesh axis,
+    swept in :func:`panel_width`-wide panels."""
     return ShardedPlan(plan, nshards)
 
 

@@ -95,12 +95,30 @@ def residual_ref(a, x, b):
     return (b.astype(ad) - acc).astype(b.dtype)
 
 
+def _to_grid(t, dt, out):
+    """``t`` rounded to ``dt``'s grid, returned as dtype ``out``.
+
+    From float32 to bfloat16 the rounding is done on the bits (to
+    nearest, ties to even, NaN kept), which gives what the cast does:
+    XLA may drop an f32 -> bf16 -> f32 cast pair as excess precision,
+    but not integer operations.
+    """
+    if dt == jnp.dtype(DTYPES["bf16"]) and t.dtype == jnp.float32:
+        bits = jax.lax.bitcast_convert_type(t, jnp.uint32)
+        bits = (bits + jnp.uint32(0x7FFF) + ((bits >> 16) & 1)) & jnp.uint32(
+            0xFFFF0000)
+        r = jax.lax.bitcast_convert_type(bits, jnp.float32)
+        return jnp.where(jnp.isnan(t), t, r).astype(out)
+    return t.astype(dt).astype(out)
+
+
 def _round_tiles(x, name, quant, b):
     """Per-(b, b)-tile ``storage_round``, vectorized over an (R, C) block.
 
     Bitwise-identical per tile to ``repro.core.quantize.storage_round``
-    (same reductions, same cast chain) but one fused pass instead of a
-    python loop over tiles — the oracle's hot rounding path.
+    (same reductions, same rounding) but one fused pass instead of a
+    python loop over tiles — the oracle's hot rounding path, and the
+    distributed engine's storage rounding (:func:`_to_grid`).
     """
     from repro.core.precision import DTYPES, NARROW, RMAX  # lazy: no cycle
     dt = jnp.dtype(DTYPES[name])
@@ -119,9 +137,9 @@ def _round_tiles(x, name, quant, b):
         amax = amax.astype(jnp.float32)
         alpha = jnp.maximum(jnp.float32(1.0),
                             amax / jnp.float32(RMAX[name]))
-        q = (t / alpha.astype(t.dtype)).astype(dt).astype(x.dtype)
+        q = _to_grid(t / alpha.astype(t.dtype), dt, x.dtype)
         return (q * alpha.astype(x.dtype)).reshape(R, C)
-    return t.astype(dt).astype(x.dtype).reshape(R, C)
+    return _to_grid(t, dt, x.dtype).reshape(R, C)
 
 
 def _name_runs(names, quants):

@@ -32,11 +32,11 @@ from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.blocked import diag_tri_inv
-from repro.core.distributed import (dist_cholesky, dist_cholesky_solve,
-                                    dist_residual)
+from repro.core.distributed import dist_cholesky, dist_refine_solve
+from repro.core.plan import build_plan, shard
 from repro.core.precision import PAPER_CONFIGS, PrecisionConfig
 from repro.core.refine import (RefineConfig, RefineResult, RefineStepper,
-                               gmres_operator, refine_operator, scaled_solve)
+                               scaled_solve)
 from repro.core.solve import cholesky_padded, refine_solve, solve_factored
 from repro.kernels import ops
 from repro.models import transformer as T
@@ -206,7 +206,7 @@ class SolverEngine:
     through the distributed block-panel solver
     (:func:`repro.core.distributed.dist_cholesky`), with every
     refinement sweep's correction solve running distributed too
-    (:func:`~repro.core.distributed.dist_cholesky_solve`). The factor
+    (:func:`~repro.core.distributed.dist_refine_solve`). The factor
     cache then stores the SHARDED factor per fingerprint — cache hits
     reuse device-resident shards, no re-gather. Smaller or non-divisible
     matrices fall back to the single-device path; ``SolveInfo
@@ -328,49 +328,15 @@ class SolverEngine:
             l = dist_cholesky(a_sh, self.mesh, cfg,
                               axis=self.dist_axis,
                               compress_comm=compress)
+            if cfg.engine == "blocked":
+                sp = shard(build_plan(n, cfg), self.mesh.shape[self.dist_axis])
+                self.metrics.inc("dist.collective_bytes",
+                                 sum(sp.collective_bytes(compress).values()))
             return l, None
         l = cholesky_padded(a, cfg)
         linvs = (diag_tri_inv(l, cfg)
                  if cfg.engine == "blocked" else None)
         return l, linvs
-
-    def _dist_refine(self, a, bmat, rcfg: RefineConfig, l,
-                     col_tol) -> RefineResult:
-        """Refinement loop whose correction solves run on the mesh.
-
-        Same contract as :func:`repro.core.solve.refine_solve` (which
-        backs the single-device path), but the base solve and every
-        sweep's correction go through
-        :func:`~repro.core.distributed.dist_cholesky_solve` against the
-        sharded factor; residuals form in the residual precision through
-        :func:`~repro.core.distributed.dist_residual`, each device's rows
-        by the fused-residual dispatch like the local path.
-        """
-        rdtype = rcfg.rdtype()
-        mesh, axis = self.mesh, self.dist_axis
-        cfg = self._cfg_for(a.shape[-1])
-        # keep A block-row-sharded for the sweep GEMMs too: the per-sweep
-        # matvec/residual is the dominant O(n^2 k) term, and a replicated
-        # A would run it on one device
-        a_r = jax.device_put(jnp.asarray(a, rdtype), NamedSharding(
-            mesh, PartitionSpec(axis, None)))
-        b_r = jnp.asarray(bmat, rdtype)
-
-        def base_solve(r):
-            x = dist_cholesky_solve(a, r.astype(l.dtype), mesh, cfg,
-                                    axis=axis, l=l)
-            return x.astype(rdtype)
-
-        def matvec(x):
-            return a_r @ x
-
-        def resid(x):
-            return dist_residual(a_r, x, b_r, mesh, cfg, axis=axis)
-
-        correct = scaled_solve(base_solve)
-        x0 = base_solve(b_r)    # unscaled, like iterative_refine
-        run = gmres_operator if rcfg.method == "gmres" else refine_operator
-        return run(matvec, correct, b_r, x0, rcfg, resid=resid, tol=col_tol)
 
     @functools.partial(annotate_function, name="repro.engine.factor")
     def factor(self, a, cache_key=None, *, fingerprint=None):
@@ -475,8 +441,9 @@ class SolverEngine:
             [b[:, None] if b.ndim == 1 else b for b in bs], axis=1)
         dist = self._use_dist(n)
         if dist:
-            res: RefineResult = self._dist_refine(
-                a, bmat, rcfg, l, jnp.asarray(col_tol))
+            res: RefineResult = dist_refine_solve(
+                a, bmat, self.mesh, self._cfg_for(n), axis=self.dist_axis,
+                refine=rcfg, l=l, col_tol=jnp.asarray(col_tol))
         else:
             res = _refine(a, bmat, l, linvs, jnp.asarray(col_tol),
                           cfg=self._cfg_for(n), rcfg=rcfg)

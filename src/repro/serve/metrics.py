@@ -19,6 +19,7 @@ name                           kind   meaning
 ``engine.factor_cache_miss``   count  factorization actually ran
 ``engine.sweeps_per_column``   obs    refinement sweeps spent, per RHS column
 ``engine.base_solve_compiles`` count  join widths a stepper's base solve met
+``dist.collective_bytes``      count  collective bytes per device, per dist factor
 ``scheduler.queue_ms``         obs    submit → solve-start latency per request
 ``scheduler.requests``         count  requests completed (rate → req/s)
 ``scheduler.slot_occupancy``   gauge  occupied / total slots, per sweep

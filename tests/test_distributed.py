@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.core as core
 from repro.core import distributed as dist
+from repro.core import plan as plan_mod
 from repro.core.plan import build_plan, shard
 from repro.launch.mesh import make_mesh
 
@@ -127,14 +128,26 @@ def test_dist_solve():
 # ---------------------------------------------------------------------------
 # sharded plan (host-side: runs without devices)
 # ---------------------------------------------------------------------------
-def test_sharded_plan_comm_schedule():
+def test_sharded_plan_comm_schedule(monkeypatch):
     """Collective precision follows the plan: early panels move at the
     ladder's coarse level, panels whose every trailing consumer computes
     fine are gathered losslessly."""
     cfg = dataclasses.replace(core.PAPER_CONFIGS["bf16x3_f32"], leaf=128)
     sp = core.shard(build_plan(1024, cfg), 4)
+    assert sp.panel_width == sp.shard_rows == 256 and sp.npanels == 4
     names = [sp.comm_name(j) for j in range(4)]
     assert names[0] == "bf16" and names[-1] == "f32", names
+    # b-wide panels (b = 128 < w = 256): one collective per panel,
+    # coarse first, lossless last, never coarser toward the diagonal
+    monkeypatch.setattr(plan_mod, "PANEL_MAX", 128)
+    spb = core.shard(build_plan(1024, cfg), 4)
+    assert spb.npanels == 8 and len(spb.comm_table()) == 8
+    levels = [spb.comm_level(j) for j in range(8)]
+    assert levels == sorted(levels), levels
+    assert spb.comm_name(0) == "bf16" and spb.comm_name(7) == "f32"
+    # every panel's all-reduce and every gather but the last one's
+    assert spb.collective_bytes(compress=False) == {
+        "f32": 8 * 128 * 128 * 4 + 7 * 4 * 256 * 128 * 4}
     # pure ladders compress every panel; f32 ladders none
     sp16 = shard(build_plan(1024, dataclasses.replace(
         core.PAPER_CONFIGS["pure_f16"], leaf=128)), 4)
@@ -144,7 +157,7 @@ def test_sharded_plan_comm_schedule():
     assert "panel 0: comm=bf16" in sp.describe()
 
 
-def test_sharded_plan_views_match_parent():
+def test_sharded_plan_views_match_parent(monkeypatch):
     """diag_plan / store_codes are views of the global tables, not a
     fresh local recursion."""
     cfg = dataclasses.replace(core.PAPER_CONFIGS["f16x3_f32"], leaf=128)
@@ -169,6 +182,20 @@ def test_sharded_plan_views_match_parent():
     fresh = build_plan(512, cfg)
     glob = sp.diag_plan(3)
     assert glob.level(3, 0) >= fresh.level(3, 0)
+    # panels of 256 columns: two a shard, two tiles each
+    monkeypatch.setattr(plan_mod, "PANEL_MAX", 256)
+    spb = shard(plan, 4)
+    assert (spb.ptiles, spb.npanels, spb.shard_rows) == (2, 8, 512)
+    for j in range(8):
+        assert spb.owner(j) == j // 2
+        dp = spb.diag_plan(j)
+        assert dp.ntiles == 2
+        assert dp.level(1, 0) == plan.level(2 * j + 1, 2 * j)
+        codes = spb.store_codes(j)
+        assert codes.shape == (16, 2)
+        for i in range(16):
+            for c in range(2):
+                assert spb.names[codes[i, c]] == plan.store_name(i, 2 * j + c)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ The topology is described inside a module fixture (never while the
 module is imported): only one process at a time may load the TPU
 library, and pytest-xdist workers all import every test file.
 """
+import dataclasses
 import os
 import re
 
@@ -116,6 +117,54 @@ def test_sharded_panel_update_compiles(topo):
                                     sharding=NamedSharding(mesh, spec))
     _compile_has_kernel(fn, s((LEAF, LEAF), P()), s((4 * M, LEAF), P("model")),
                         s((4 * M, M), P("model")))
+
+
+def test_sharded_narrow_panel_factor_compiles(topo, monkeypatch):
+    """The distributed factor with panels narrower than a shard's rows
+    (n = 4096 over 4 chips: w = 1024, b = 512), its trailing update in
+    ``lax.cond`` column blocks, compiles with every kernel it calls."""
+    from repro.core import distributed as dist
+    from repro.core import plan as plan_mod
+    jax.clear_caches()        # the panel width is read at trace time
+    monkeypatch.setattr(plan_mod, "PANEL_MAX", 512)
+    mesh = Mesh(topo.devices[:4], ("model",))
+    cfg = dataclasses.replace(PAPER_CONFIGS["bf16_f32"], kernel_impl="pallas")
+    a = jax.ShapeDtypeStruct((4096, 4096), jnp.float32,
+                             sharding=NamedSharding(mesh, P("model", None)))
+    calls = _kernel_calls(
+        lambda a: dist.dist_cholesky(a, mesh, cfg, compress_comm=True), a)
+    assert {"qgemm", "potrf_leaf", "tri_inv_leaf", "panel_update"} <= calls
+
+
+def test_sharded_storage_rounding_survives_compile(topo):
+    """Each shard rounds its slice of a solved panel onto the plan's
+    storage grid (``_round_panel_rows``); an f32 -> bf16 -> f32 cast
+    pair there could be dropped by XLA as excess precision, so the
+    rounding to bf16 is done on the bits (``kernels.ref._to_grid``). In
+    the program compiled for the v5e every panel that stores a tile in
+    bf16 keeps its bit mask feeding an f32 value (bf16x3_f32 at
+    n = 1024, leaf 128: the two-level bf16_f32 ladder stores every tile
+    in f32, so its sweep has no such rounding)."""
+    from repro.core import distributed as dist
+    from repro.core.plan import shard
+    mesh = Mesh(topo.devices[:4], ("model",))
+    cfg = dataclasses.replace(PAPER_CONFIGS["bf16x3_f32"], leaf=128,
+                              kernel_impl="pallas")
+    sp = shard(build_plan(1024, cfg), 4)
+    low = sp.names.index("bf16")
+    rounded = sum(bool((sp.store_codes(j) == low).any())
+                  for j in range(sp.npanels))
+    a = jax.ShapeDtypeStruct((1024, 1024), jnp.float32,
+                             sharding=NamedSharding(mesh, P("model", None)))
+    text = jax.jit(lambda a: dist.dist_cholesky(
+        a, mesh, cfg, compress_comm=True)).lower(a).compile().as_text()
+    scope = r"[^\n]*/trsm/"
+    masked = set(re.findall(r"%([\w.\-]+) = u32\[[^\n]* and\(" + scope
+                            + "and", text))
+    back = re.findall(r"= f32\[[^\n]* bitcast-convert\(%([\w.\-]+)\)"
+                      + scope, text)
+    assert rounded == sp.npanels == 4
+    assert len(masked & set(back)) >= rounded, (masked, back)
 
 
 def _kernel_calls(fn, *args):
